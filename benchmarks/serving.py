@@ -145,6 +145,7 @@ from check_bench import (FLOOR_TOK_S, MIN_KVQ_BYTES_RATIO,
                          check_prefix_cache, check_sharded)
 from repro import obs as obs_lib
 from repro.configs.base import ModelConfig
+from repro.launch import compile_cache
 from repro.models import transformer as tf
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.sampling import SamplingParams
@@ -630,26 +631,36 @@ def bench_sharded(smoke: bool) -> dict:
     child's marker line; with >= 2 devices it runs in-process."""
     if jax.device_count() >= 2:
         return _bench_sharded_inner(smoke)
-    import os
+    return _cpu_child("--sharded-subprocess", smoke)
+
+
+#: stdout marker a two-device child prints its JSON result after
+_CHILD_MARKER = "CHILD_JSON:"
+
+
+def _cpu_child(flag: str, smoke: bool) -> dict:
+    """Re-exec this file with ``flag`` as a child on two forced host CPU
+    devices and return the JSON it prints after _CHILD_MARKER. jax
+    freezes the device count at first backend init, so a one-device
+    parent cannot build a TP=2 mesh itself. The child is pinned to the
+    CPU: where the parent runs on an accelerator it already holds it. A
+    child that prints no result raises, so the benchmark exits non-zero
+    instead of recording a partial section."""
     import subprocess
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=2").strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, os.path.abspath(__file__), "--sharded-subprocess"]
+    cmd = [sys.executable, os.path.abspath(__file__), flag]
     if smoke:
         cmd.append("--smoke")
     proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
                           cwd=root)
     for line in proc.stdout.splitlines():
-        if line.startswith(_SHARDED_MARKER):
-            return json.loads(line[len(_SHARDED_MARKER):])
-    return {"error": "sharded subprocess produced no result: "
-                     + (proc.stderr or proc.stdout)[-500:]}
-
-
-#: stdout marker the --sharded-subprocess child prints its JSON after
-_SHARDED_MARKER = "SHARDED_JSON:"
+        if line.startswith(_CHILD_MARKER):
+            return json.loads(line[len(_CHILD_MARKER):])
+    raise RuntimeError(f"{flag} child (exit {proc.returncode}) produced no "
+                       "result: " + (proc.stderr or proc.stdout)[-2000:])
 
 
 def _prefix_trace(cfg, n_users: int, rate_req_s: float, seed: int = 21):
@@ -750,10 +761,6 @@ def _bench_prefix_tp_inner(smoke: bool) -> dict:
     return out
 
 
-#: stdout marker the --prefix-subprocess child prints its JSON after
-_PREFIX_MARKER = "PREFIX_JSON:"
-
-
 def bench_prefix_cache(cfg, params, smoke: bool) -> dict:
     """Prefix-caching section (module docstring, ``prefix_cache``): the
     shared-system-prompt Poisson trace cache-off vs cache-on at TP=1,
@@ -792,31 +799,9 @@ def bench_prefix_cache(cfg, params, smoke: bool) -> dict:
           f"{res['prefill_tokens_ratio']} down, peak blocks x"
           f"{res['peak_blocks_ratio']} down, tokens identical: "
           f"{bool(res['tokens_identical'])}")
-    if jax.device_count() >= 2:
-        res["tp"] = _bench_prefix_tp_inner(smoke)
-        return res
-    import os
-    import subprocess
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=2").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, os.path.abspath(__file__), "--prefix-subprocess"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          cwd=root)
-    for line in proc.stdout.splitlines():
-        if line.startswith(_PREFIX_MARKER):
-            res["tp"] = json.loads(line[len(_PREFIX_MARKER):])
-            return res
-    res["tp"] = {"error": "prefix tp subprocess produced no result: "
-                          + (proc.stderr or proc.stdout)[-500:]}
+    res["tp"] = (_bench_prefix_tp_inner(smoke) if jax.device_count() >= 2
+                 else _cpu_child("--prefix-subprocess", smoke))
     return res
-
-
-#: stdout marker the --kvq-subprocess child prints its JSON after
-_KVQ_MARKER = "KVQ_TP_JSON:"
 
 
 def _kvq_serve(cfg, params, max_new: int, *, kv_quant, attend_impl="gather",
@@ -914,25 +899,8 @@ def bench_kv_quant(cfg, params, smoke: bool) -> dict:
     res["pallas_tokens_identical"] = int(toks_pl == toks_i8)
     print(f"[serving] kv_quant: int8 gather == int8 pallas: "
           f"{bool(res['pallas_tokens_identical'])}")
-    if jax.device_count() >= 2:
-        res["tp"] = _bench_kvq_tp_inner(smoke)
-        return res
-    import subprocess
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=2").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, os.path.abspath(__file__), "--kvq-subprocess"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          cwd=root)
-    for line in proc.stdout.splitlines():
-        if line.startswith(_KVQ_MARKER):
-            res["tp"] = json.loads(line[len(_KVQ_MARKER):])
-            return res
-    res["tp"] = {"error": "kv_quant tp subprocess produced no result: "
-                          + (proc.stderr or proc.stdout)[-500:]}
+    res["tp"] = (_bench_kvq_tp_inner(smoke) if jax.device_count() >= 2
+                 else _cpu_child("--kvq-subprocess", smoke))
     return res
 
 
@@ -1094,15 +1062,15 @@ def main(argv=None) -> int:
     ap.add_argument("--kvq-subprocess", action="store_true",
                     help=argparse.SUPPRESS)  # internal: kv_quant tp child
     args = ap.parse_args(argv)
+    compile_cache.setup()
 
-    if args.sharded_subprocess:
-        print(_SHARDED_MARKER + json.dumps(_bench_sharded_inner(args.smoke)))
-        return 0
-    if args.prefix_subprocess:
-        print(_PREFIX_MARKER + json.dumps(_bench_prefix_tp_inner(args.smoke)))
-        return 0
-    if args.kvq_subprocess:
-        print(_KVQ_MARKER + json.dumps(_bench_kvq_tp_inner(args.smoke)))
+    child = (_bench_sharded_inner if args.sharded_subprocess
+             else _bench_prefix_tp_inner if args.prefix_subprocess
+             else _bench_kvq_tp_inner if args.kvq_subprocess else None)
+    if child is not None:
+        out = child(args.smoke)
+        out["backend"] = jax.default_backend()
+        print(_CHILD_MARKER + json.dumps(out))
         return 0
 
     cfg = _cfg(args.smoke)

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro import obs as repro_obs
 from repro.configs.base import ModelConfig
+from repro.launch import compile_cache
 from repro.models import transformer as tf
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.sampling import SamplingParams
@@ -116,6 +117,7 @@ def main():
                     help="write a Chrome-trace (Perfetto-loadable) JSON of "
                          "request lifecycles + engine phase spans here")
     args = ap.parse_args()
+    compile_cache.setup()
 
     cfg = ModelConfig(
         name="serve-demo", family="dense",

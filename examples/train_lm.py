@@ -23,6 +23,7 @@ from repro.configs.base import ModelConfig
 from repro.data.pipeline import DataConfig, DataIterator, SyntheticLMDataset
 from repro.checkpoint import manager as ckpt
 from repro.distributed.fault_tolerance import StragglerDetector
+from repro.launch import compile_cache
 from repro.optim import adamw
 from repro.train import step as step_lib
 
@@ -63,6 +64,7 @@ def main():
     ap.add_argument("--accum", type=int, default=2)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    compile_cache.setup()
 
     cfg = build_cfg(args.act, args.loss, small=args.small)
     n_params = cfg.param_counts()["total"]

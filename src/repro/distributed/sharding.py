@@ -22,10 +22,12 @@ sharded over the data axis instead (sequence parallelism).
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Any, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
@@ -156,6 +158,22 @@ def serve_param_shardings(cfg, params, mesh: Mesh):
     if "embed" in sh:
         sh["embed"] = jax.tree.map(lambda _: repl, sh["embed"])
     return sh
+
+
+def init_serve_params(cfg, key, mesh: Optional[Mesh] = None):
+    """Seeded random serving params in ``cfg.dtype``, built by one jit.
+
+    With a mesh, the jit's out_shardings are ``serve_param_shardings``:
+    each device draws only its own shard, so a sharded load never stages
+    the whole tree on one device. The values do not depend on the mesh
+    (jax's threefry PRNG is partitionable), so TP=N serves the same
+    weights as TP=1."""
+    from repro.models import transformer as _tf
+
+    init = functools.partial(_tf.init, cfg, dtype=jnp.dtype(cfg.dtype))
+    sh = (None if mesh is None else
+          serve_param_shardings(cfg, jax.eval_shape(init, key), mesh))
+    return jax.jit(init, out_shardings=sh)(key)
 
 
 #: cache-tree leaf names whose dim -2 is the kv-head axis (dense stacked

@@ -478,7 +478,7 @@ def mla_decode(q_eff, q_rope, c_pool, r_pool, tables, k_len, *, scale: float,
 # whole point of KVPager staying shard-agnostic). The per-shard kernel is
 # bitwise the single-device kernel on a narrower head axis, and head
 # slices never interact inside attention, so no collective appears inside
-# the wrapped region (check_rep=False: outputs are head-sharded, not
+# the wrapped region (check_vma=False: outputs are head-sharded, not
 # replicated).
 # ---------------------------------------------------------------------------
 def shard_local_gqa(attend_fn, mesh, q, k_pool, v_pool, tables, k_len,
@@ -493,7 +493,6 @@ def shard_local_gqa(attend_fn, mesh, q, k_pool, v_pool, tables, k_len,
     the unsharded kernel would, so TP layouts stay token-identical.
     Caller guarantees KH % mesh.shape["model"] == 0.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     in_specs = [PS(None, "model", None, None),        # q (B, KH, G, hd)
@@ -510,11 +509,11 @@ def shard_local_gqa(attend_fn, mesh, q, k_pool, v_pool, tables, k_len,
     else:
         fn = attend_fn
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=PS(None, "model", None, None),
-        check_rep=False,
+        check_vma=False,
     )(*args)
 
 
@@ -528,10 +527,9 @@ def shard_local_mla(attend_fn, mesh, q_eff, q_rope, c_pool, r_pool, tables,
     (head-parallel over the absorbed queries). Output (B,H,R) is
     H-sharded. Caller guarantees H % mesh.shape["model"] == 0.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
-    return shard_map(
+    return jax.shard_map(
         attend_fn, mesh=mesh,
         in_specs=(PS(None, "model", None),            # q_eff (B, H, R)
                   PS(None, "model", None),            # q_rope (B, H, P)
@@ -540,7 +538,7 @@ def shard_local_mla(attend_fn, mesh, q_eff, q_rope, c_pool, r_pool, tables,
                   PS(None, None),                     # tables (B, M)
                   PS(None)),                          # k_len (B,)
         out_specs=PS(None, "model", None),
-        check_rep=False,
+        check_vma=False,
     )(q_eff, q_rope, c_pool, r_pool, tables, k_len)
 
 

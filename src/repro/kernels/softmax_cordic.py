@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.cordic import FixedConfig, MRSchedule, PAPER_FIXED, PAPER_SCHEDULE
 from repro.kernels.cordic_act import (
@@ -126,13 +127,27 @@ def _log_softmax_kernel(x_ref, o_ref, *, sched: MRSchedule, cfg: FixedConfig,
     o_ref[...] = (u - lns).astype(o_ref.dtype)
 
 
-def _row_block(rows: int, cols_p: int, target_bytes: int = 1 << 20) -> int:
-    """Rows per block: whole rows only, ~1 MiB of f32 input per tile."""
-    br = max(1, target_bytes // (4 * cols_p))
-    br = min(br, rows)
+#: row-tile-sized f32 buffers live at once in a row kernel: the double-
+#: buffered input and output blocks plus the CORDIC temporaries. Mosaic
+#: asks 27.5 MiB of scoped VMEM for an (8, 64000) f32 tile on v5e, i.e.
+#: ~14 tiles; 16 leaves headroom.
+_LIVE_TILES = 16
+#: working-set target per grid step, inside the 16 MiB default scoped
+#: VMEM limit of v5e/v6e
+_VMEM_BUDGET = 12 << 20
+_DEFAULT_SCOPED_VMEM = 16 << 20
+
+
+def _row_block(rows: int, cols_p: int) -> tuple:
+    """Rows per block and the block's VMEM working set in bytes: whole
+    rows only, sized so the working set stays near _VMEM_BUDGET. The
+    sublane floor of 8 rows can overshoot it for very wide rows (a 64000-
+    column vocabulary); the caller then raises the scoped VMEM limit."""
+    row_bytes = 4 * cols_p * _LIVE_TILES
+    br = min(max(1, _VMEM_BUDGET // row_bytes), rows)
     if rows >= 8:
         br = max(8, (br // 8) * 8)
-    return br
+    return br, br * row_bytes
 
 
 def _rowwise_call(x: jax.Array, body, sched: MRSchedule, cfg: FixedConfig,
@@ -143,16 +158,19 @@ def _rowwise_call(x: jax.Array, body, sched: MRSchedule, cfg: FixedConfig,
     if cols_p != cols:
         pad = jnp.full((rows, cols_p - cols), np.float32(-1e30), x.dtype)
         x = jnp.concatenate([x, pad], axis=1)
-    br = _row_block(rows, cols_p)
+    br, vmem = _row_block(rows, cols_p)
     grid = (pl.cdiv(rows, br),)
     spec = pl.BlockSpec((br, cols_p), lambda i: (i, 0))
     kern = functools.partial(body, sched=sched, cfg=cfg, n_valid=cols)
+    params = (pltpu.CompilerParams(vmem_limit_bytes=vmem)
+              if vmem > _DEFAULT_SCOPED_VMEM else None)
     out = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((rows, cols_p), x.dtype),
         grid=grid,
         in_specs=[spec],
         out_specs=spec,
+        compiler_params=params,
         interpret=interpret,
     )(x)
     return out[:, :cols]

@@ -13,12 +13,19 @@ import jax
 import numpy as np
 
 
+def _auto(n: int):
+    # jax.make_mesh defaults to Explicit axes, which type-check shardings
+    # at trace time; the serving and dry-run code is written for the
+    # partitioner (sharding constraints + shard_map), i.e. Auto axes
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 ("data","model") single pod; 2x16x16 ("pod","data","model")
     for the two-pod (512-chip) dry-run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -34,7 +41,7 @@ def make_host_mesh(model_parallel: int = 1):
             "the devices visible to jax (force more CPU devices with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"), axis_types=_auto(2))
 
 
 def mesh_or_none(model_parallel: int = 1) -> Optional[jax.sharding.Mesh]:

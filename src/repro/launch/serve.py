@@ -17,7 +17,9 @@ import numpy as np
 
 from repro import configs
 from repro import obs as repro_obs
-from repro.models import transformer as tf
+from repro.distributed import sharding as shd
+from repro.launch import compile_cache
+from repro.launch import mesh as mesh_lib
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.sampling import SamplingParams
 
@@ -97,6 +99,7 @@ def main(argv=None):
                          "of request lifecycles + engine phase spans to "
                          "this path")
     args = ap.parse_args(argv)
+    compile_cache.setup()
 
     obs = (repro_obs.Observability(trace=args.trace_out is not None)
            if (args.metrics_json or args.trace_out) else None)
@@ -106,9 +109,13 @@ def main(argv=None):
         import dataclasses
 
         cfg = dataclasses.replace(cfg, input_mode="tokens")
+    dev = jax.devices()
+    print(f"[serve] platform={dev[0].platform} kind={dev[0].device_kind} "
+          f"count={len(dev)}")
     print(f"[serve] arch={cfg.name} slots={args.slots} kv={args.kv_impl} "
-          f"kv_quant={args.kv_quant} tp={args.tp or 1}")
-    params = tf.init(cfg, jax.random.PRNGKey(0))
+          f"kv_quant={args.kv_quant} tp={args.tp or 1} dtype={cfg.dtype}")
+    mesh = mesh_lib.mesh_or_none(args.tp)
+    params = shd.init_serve_params(cfg, jax.random.PRNGKey(0), mesh)
     # temperature <= 0 resolves to greedy inside SamplingParams
     sampling = SamplingParams(temperature=args.temperature, top_k=args.top_k)
     eng = ServeEngine(cfg, params, slots=args.slots, max_len=args.max_len,
@@ -122,7 +129,7 @@ def main(argv=None):
                       max_prefill_tokens=args.max_prefill_tokens or None,
                       prefix_cache=args.prefix_cache,
                       prefix_eviction=args.prefix_eviction,
-                      tp=args.tp or None,
+                      mesh=mesh,
                       obs=obs)
     if eng.mesh is not None:
         print(f"[serve] mesh: {dict(eng.mesh.shape)} over "

@@ -19,6 +19,7 @@ import argparse
 import dataclasses
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.optim import adamw
 from repro.train import loop as loop_lib
 
@@ -37,6 +38,7 @@ def main(argv=None):
                     help="int8 error-feedback gradient compression")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args(argv)
+    compile_cache.setup()
 
     cfg = (configs.get_smoke(args.arch, act_impl=args.act_impl) if args.smoke
            else configs.get_config(args.arch, act_impl=args.act_impl))

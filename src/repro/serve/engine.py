@@ -156,6 +156,7 @@ partitioner; engine init enforces head % tp == 0 on that path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -575,9 +576,17 @@ class ServeEngine:
                     self.pager, self.block_len, policy=prefix_eviction)
             else:
                 self.prefix = None
-            self._caches = tf.init_paged_cache(
-                cfg, slots, num_blocks, self.block_len, self.max_blocks,
-                jnp.float32)
+            init_caches = functools.partial(
+                tf.init_paged_cache, cfg, slots, num_blocks, self.block_len,
+                self.max_blocks, jnp.float32)
+            # head-sharded pools, everything else (tables/lens/latent)
+            # replicated. Built by one jit, so each device makes only its
+            # own shard: the pools are never staged whole on one device.
+            self._cache_sh = (None if self.mesh is None else
+                              shd.kv_cache_shardings(
+                                  jax.eval_shape(init_caches), self.mesh))
+            self._caches = jax.jit(init_caches,
+                                   out_shardings=self._cache_sh)()
             # device bytes per block across layers (codes + quant scales):
             # feeds the pager's kv.pool.bytes_in_use gauge and the
             # kv.quant.bytes_per_token series the bench gates on
@@ -590,13 +599,9 @@ class ServeEngine:
                     jnp.zeros((), jnp.int32))
 
             if self.mesh is not None:
-                # head-sharded pools, everything else (tables/lens/latent)
-                # replicated; explicit in/out shardings on every jit so
-                # decode stays ONE dispatch and cache state round-trips
-                # without resharding (donation stays in place)
-                self._cache_sh = shd.kv_cache_shardings(self._caches,
-                                                        self.mesh)
-                self._caches = jax.device_put(self._caches, self._cache_sh)
+                # explicit in/out shardings on every jit so decode stays
+                # ONE dispatch and cache state round-trips without
+                # resharding (donation stays in place)
                 repl = self._repl
                 self._prefill = jax.jit(
                     make_paged_prefill_step(cfg), donate_argnums=(1,),

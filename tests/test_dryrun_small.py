@@ -27,16 +27,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # sharding rules (in-process, mesh over 1 device is fine for spec logic)
 # ---------------------------------------------------------------------------
 def _mesh_16x16_abstract():
-    """AbstractMesh carries only names/shapes — perfect for spec logic.
-
-    The constructor signature changed across jax releases: <=0.4.35 took
-    (sizes, names); 0.4.36+ takes a tuple of (name, size) pairs."""
+    """AbstractMesh carries only names/shapes — perfect for spec logic."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh((("data", 16), ("model", 16)))
-    except (TypeError, ValueError):
-        return AbstractMesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_spec_divisibility_fallback():

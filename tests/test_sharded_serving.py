@@ -63,7 +63,7 @@ multi_device = pytest.mark.skipif(
 # 1a. spec_for_axes unit tests (AbstractMesh: no devices needed)
 # ---------------------------------------------------------------------------
 def _amesh(data=1, model=4):
-    return AbstractMesh((("data", data), ("model", model)))
+    return AbstractMesh((data, model), ("data", "model"))
 
 
 def test_spec_divisibility_fallback_replicates():
@@ -147,6 +147,34 @@ def test_mesh_or_none_builds_model_axis():
     assert mesh.shape["model"] == 2
     assert mesh.shape["data"] == _NDEV // 2
     assert mesh.axis_names == ("data", "model")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_init_serve_params_in_cfg_dtype(dtype):
+    cfg = dataclasses.replace(_gqa_cfg(), dtype=dtype)
+    key = jax.random.PRNGKey(3)
+    got = shd.init_serve_params(cfg, key)
+    assert {x.dtype for x in jax.tree.leaves(got)} == {jnp.dtype(dtype)}
+    # the same draws as an eager f32 init, up to the one jit's fusion
+    # (f32) or the cast (bf16: half an ulp of its 8-bit mantissa)
+    rtol = 1e-6 if dtype == "float32" else 2.0 ** -8
+    jax.tree.map(lambda g, w: np.testing.assert_allclose(
+        np.asarray(g, np.float32), w, rtol=rtol, atol=1e-7),
+        got, tf.init(cfg, key, dtype=jnp.float32))
+
+
+@multi_device
+def test_init_serve_params_sharded_matches_unsharded():
+    # each device draws only its own shard, and draws the same values
+    cfg = dataclasses.replace(_gqa_cfg(), dtype="bfloat16")
+    key = jax.random.PRNGKey(3)
+    mesh = mesh_lib.mesh_or_none(4)
+    got = shd.init_serve_params(cfg, key, mesh)
+    want_sh = shd.serve_param_shardings(cfg, got, mesh)
+    jax.tree.map(lambda x, s: x.sharding.is_equivalent_to(s, x.ndim)
+                 or pytest.fail(f"{x.sharding} != {s}"), got, want_sh)
+    jax.tree.map(np.testing.assert_array_equal, got,
+                 shd.init_serve_params(cfg, key))
 
 
 # ---------------------------------------------------------------------------
