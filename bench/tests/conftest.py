@@ -1,0 +1,16 @@
+"""CPU self-tests of the benchmark, run by path:
+
+    JAX_PLATFORMS=cpu python3 -m pytest -q bench/tests
+
+They check the reference against the program's own forward pass at a
+small size, the trace reduction against a recorded trace, the traffic
+generator's determinism, and that a broken timed path or the float8
+control comes out not correct.
+"""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, BENCH)
+sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "src"))
