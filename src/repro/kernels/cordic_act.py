@@ -379,6 +379,7 @@ def act_2d(x: jax.Array, op: str, *, sched: MRSchedule = PAPER_SCHEDULE,
         in_specs=[spec],
         out_specs=spec,
         interpret=interpret,
+        name="cordic_act",
     )(x)
 
 
@@ -395,6 +396,7 @@ def act_q_2d(x_q: jax.Array, *, sched: MRSchedule = PAPER_SCHEDULE,
         in_specs=[spec],
         out_specs=spec,
         interpret=interpret,
+        name="cordic_act_q",
     )(x_q)
 
 
@@ -414,4 +416,5 @@ def silu_mul_2d(gate: jax.Array, up: jax.Array, *,
         in_specs=[spec, spec],
         out_specs=spec,
         interpret=interpret,
+        name="cordic_silu_mul",
     )(gate, up)

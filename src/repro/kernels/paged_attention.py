@@ -375,6 +375,7 @@ def gqa_decode(q, k_pool, v_pool, tables, k_len, *, scale: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), jnp.float32),
         interpret=interpret,
+        name="gqa_decode",
     )(*operands)
 
 
@@ -462,6 +463,7 @@ def mla_decode(q_eff, q_rope, c_pool, r_pool, tables, k_len, *, scale: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
         interpret=interpret,
+        name="mla_decode",
     )(tables, k_len, q_eff, q_rope, c_pool, r_pool)
     return out
 

@@ -150,9 +150,10 @@ def _row_block(rows: int, cols_p: int) -> tuple:
     return br, br * row_bytes
 
 
-def _rowwise_call(x: jax.Array, body, sched: MRSchedule, cfg: FixedConfig,
-                  interpret: bool) -> jax.Array:
-    """Pad columns to the 128-lane boundary and run a whole-row kernel."""
+def _rowwise_call(x: jax.Array, body, name: str, sched: MRSchedule,
+                  cfg: FixedConfig, interpret: bool) -> jax.Array:
+    """Pad columns to the 128-lane boundary and run a whole-row kernel
+    (``name`` names it in compiled HLO and profiler traces)."""
     rows, cols = x.shape
     cols_p = max(128, -(-cols // 128) * 128)
     if cols_p != cols:
@@ -172,6 +173,7 @@ def _rowwise_call(x: jax.Array, body, sched: MRSchedule, cfg: FixedConfig,
         out_specs=spec,
         compiler_params=params,
         interpret=interpret,
+        name=name,
     )(x)
     return out[:, :cols]
 
@@ -183,11 +185,13 @@ def softmax_2d(x: jax.Array, *, sched: MRSchedule = PAPER_SCHEDULE,
     Columns are padded to the 128-lane boundary; padded lanes are masked
     inside the kernel (they contribute exactly 0 to the row sum).
     """
-    return _rowwise_call(x, _softmax_kernel, sched, cfg, interpret)
+    return _rowwise_call(x, _softmax_kernel, "cordic_softmax", sched, cfg,
+                         interpret)
 
 
 def log_softmax_2d(x: jax.Array, *, sched: MRSchedule = PAPER_SCHEDULE,
                    cfg: FixedConfig = PAPER_FIXED,
                    interpret: bool = False) -> jax.Array:
     """Fused CORDIC log-softmax over the last axis of a 2D array."""
-    return _rowwise_call(x, _log_softmax_kernel, sched, cfg, interpret)
+    return _rowwise_call(x, _log_softmax_kernel, "cordic_log_softmax", sched,
+                         cfg, interpret)

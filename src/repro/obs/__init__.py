@@ -10,15 +10,19 @@ with. It bundles
 * ``trace`` — an optional :class:`repro.obs.trace.TraceRecorder` emitting
   Chrome-trace (Perfetto-loadable) request-lifecycle and engine-phase
   events against the same monotonic clock;
-* ``phase(name)`` — a context manager timing one engine phase into both
-  (histogram ``engine.phase.<name>_ms`` + an "X" span on the engine
-  track).
+* ``phase(name)`` — a context manager timing one engine phase into the
+  histogram ``engine.phase.<name>_ms``, an "X" span on the engine track
+  (with tracing on), the handle's ``spans`` log, and a
+  ``jax.profiler.TraceAnnotation`` named ``engine.<name>``, so that a
+  profiler trace (``jax.profiler.trace(dir)``) shows the engine's phases
+  on the same clock as the device's ops, nested as the calls nest.
 
 ``NULL`` is the disabled singleton: identical surface, no clock reads, no
-allocation — instrumented code writes through it unconditionally, which is
-what keeps observability *off-by-default-cheap* and the emitted tokens
-bit-identical with observability on or off (nothing here ever touches jax
-or a traced value; see tests/test_obs.py for the enforced contract).
+annotation, no allocation — instrumented code writes through it
+unconditionally, which is what keeps observability *off-by-default-cheap*
+and the emitted tokens bit-identical with observability on or off
+(nothing here touches a traced value; see tests/test_obs.py for the
+enforced contract).
 
 Saturation accounting closes the loop with the paper's overflow-free-Q2.14
 claim: :func:`repro.core.fixed_point.set_saturation_observer` feeds every
@@ -29,9 +33,12 @@ metric state is ever traced into a jitted function), and
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 from typing import Any, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                NULL_REGISTRY)
@@ -45,11 +52,12 @@ __all__ = [
 
 
 class _PhaseSpan:
-    """Times one engine phase into a histogram and (optionally) the trace.
-    Re-entered per use; allocation-free reuse is not worth the aliasing
-    risk at one object per phase per step."""
+    """Times one engine phase into a histogram, the handle's span log,
+    (optionally) the Chrome trace, and a profiler annotation. Re-entered
+    per use; allocation-free reuse is not worth the aliasing risk at one
+    object per phase per step."""
 
-    __slots__ = ("_obs", "_name", "_hist", "_t0")
+    __slots__ = ("_obs", "_name", "_hist", "_t0", "_ann")
 
     def __init__(self, obs: "Observability", name: str, hist):
         self._obs = obs
@@ -57,16 +65,30 @@ class _PhaseSpan:
         self._hist = hist
 
     def __enter__(self):
+        # a TraceMe: close to free when no profiler is collecting
+        self._ann = TraceAnnotation(f"engine.{self._name}")
+        self._ann.__enter__()
         self._t0 = self._obs.now()
         return self
 
+    def annotate(self, **args) -> None:
+        """Arguments of the profiler span (shown beside its name)."""
+        self._ann.set_metadata(**args)
+
     def __exit__(self, exc_type, exc, tb):
         t1 = self._obs.now()
+        self._ann.__exit__(exc_type, exc, tb)
         dt = t1 - self._t0
         self._hist.observe(dt * 1e3)
+        self._obs.spans.append((self._name, self._t0, t1))
         if self._obs.trace is not None:
             self._obs.trace.complete(self._name, self._t0 * 1e6, dt * 1e6)
         return False
+
+
+#: phase spans an enabled handle keeps in ``Observability.spans``, newest
+#: last: about 18,000 serving steps at seven phases a step
+SPAN_LOG = 1 << 17
 
 
 class Observability:
@@ -82,6 +104,10 @@ class Observability:
         self.metrics = MetricsRegistry()
         self.trace: Optional[TraceRecorder] = (
             TraceRecorder(process_name) if trace else None)
+        #: the newest ``SPAN_LOG`` phase spans, ``(name, start, end)`` in
+        #: seconds on this handle's clock (``epoch`` + t is the absolute
+        #: ``time.perf_counter()``): what a reader lays over a device trace
+        self.spans: collections.deque = collections.deque(maxlen=SPAN_LOG)
 
     @property
     def epoch(self) -> float:
@@ -100,7 +126,8 @@ class Observability:
 
     def phase(self, name: str) -> _PhaseSpan:
         """``with obs.phase("dispatch"): ...`` — records the wall time into
-        histogram ``engine.phase.<name>_ms`` and an engine-track span."""
+        histogram ``engine.phase.<name>_ms``, ``spans``, an engine-track
+        span, and the profiler span ``engine.<name>``."""
         return _PhaseSpan(
             self, name,
             self.metrics.histogram(f"engine.phase.{name}_ms", unit="ms"))
@@ -127,6 +154,9 @@ class _NullPhase:
     def __enter__(self):
         return self
 
+    def annotate(self, **args) -> None:
+        pass
+
     def __exit__(self, exc_type, exc, tb):
         return False
 
@@ -141,6 +171,7 @@ class _NullObservability:
     enabled = False
     metrics = NULL_REGISTRY
     trace = None
+    spans = ()
     epoch = 0.0
 
     def now(self) -> float:

@@ -102,7 +102,9 @@ independent of slot placement, batch composition, and KV layout.
 Observability (repro.obs): construct the engine with ``obs=Observability()``
 (optionally ``trace=True`` for a Chrome-trace/Perfetto request-lifecycle +
 engine-phase timeline) and read ``obs.metrics.snapshot()`` afterwards. All
-instrumentation is host-side: nothing here feeds a jitted function, so
+instrumentation is host-side (each phase span is also a profiler span,
+``engine.<phase>``, so ``jax.profiler.trace(dir)`` shows the engine's
+phases beside the device's ops): nothing here feeds a jitted function, so
 compile counts and emitted tokens are bit-identical with observability on
 or off (CI-enforced in tests/test_obs.py). The full metric-name reference
 (every ``engine.*`` / ``kv.pool.*`` / ``prefix.*`` / ``fixed_point.*``
@@ -1137,6 +1139,19 @@ class ServeEngine:
             if row.final:
                 self._complete_prefill(row.req, row.slot, logits[i:i + 1])
 
+    def _prefill_dispatch(self, rows: List[PrefillRow]) -> None:
+        """One prefill dispatch (a paged group, or one dense row) under
+        its ``prefill_dispatch`` phase span."""
+        ob = self.obs
+        with ob.phase("prefill_dispatch") as span:
+            if ob.enabled:
+                span.annotate(rows=len(rows),
+                              tokens=sum(r.width for r in rows))
+            if self.kv_impl == "paged":
+                self._dispatch_prefill_paged(rows)
+            else:
+                self._dispatch_prefill_dense(rows[0])
+
     def _dispatch_prefill_dense(self, row: PrefillRow) -> None:
         """One dense prefill row. A fresh row starts from an empty batch-1
         cache; a chunk continuation resumes the host-held partial cache.
@@ -1170,7 +1185,8 @@ class ServeEngine:
         """Run this iteration's scheduled prefill rows; returns how many.
         Paged rows pack into multi-row dispatches of up to prefill_batch;
         dense rows dispatch one at a time (fresh cache per request)."""
-        rows = self.scheduler.plan(self._admit_slot)
+        with self.obs.phase("plan"):
+            rows = self.scheduler.plan(self._admit_slot)
         if not rows:
             return 0
         self._m_pre_rows.inc(len(rows))
@@ -1190,15 +1206,15 @@ class ServeEngine:
                 w = max(gw, row.width)
                 if group and (len(group) >= self.prefill_batch or any(
                         r.start + w > self.max_len for r in group + [row])):
-                    self._dispatch_prefill_paged(group)
+                    self._prefill_dispatch(group)
                     group, w = [], row.width
                 group.append(row)
                 gw = w
             if group:
-                self._dispatch_prefill_paged(group)
+                self._prefill_dispatch(group)
         else:
             for row in rows:
-                self._dispatch_prefill_dense(row)
+                self._prefill_dispatch([row])
         return len(rows)
 
     def step(self) -> int:
@@ -1222,10 +1238,10 @@ class ServeEngine:
         # every trace this iteration performs (prefill/decode/clear/insert)
         # sees the engine's mesh (or None) via the ambient context — model
         # code reads it to place the logits constraint / shard_map attention
-        with shd.serving_mesh(self.mesh):
-            return self._step_impl()
+        with shd.serving_mesh(self.mesh), self.obs.phase("step") as span:
+            return self._step_impl(span)
 
-    def _step_impl(self) -> int:
+    def _step_impl(self, span) -> int:
         ob = self.obs
         t_step = time.perf_counter()
         self._m_steps.inc()
@@ -1236,6 +1252,8 @@ class ServeEngine:
         decodable = [s for s in range(self.slots)
                      if self._active[s] is not None and s not in chunking]
         self._m_occ.set(len(decodable))
+        if ob.enabled:
+            span.annotate(decodable=len(decodable), prefill_rows=n_rows)
         if ob.trace is not None:
             ob.trace.counter("engine.load", ob.now_us(),
                              {"queue_depth": len(self._queue),
@@ -1270,19 +1288,10 @@ class ServeEngine:
                 jnp.asarray(self._rids), jnp.asarray(self._steps),
                 jnp.asarray(self._temps), jnp.asarray(self._top_ks),
                 jnp.asarray(self._greedy), self._base_key)
-        if self.mesh is None:
-            with ob.phase("host_sync"):
-                nxt = np.asarray(nxt)
-        else:
-            # split the device wait: host_sync blocks on the cache state
-            # (the per-shard attention datapath), collective covers the
-            # remaining tail — the logits all-gather + sampling — so the
-            # one serving collective's cost shows up in the phase
-            # breakdown and the Chrome trace
-            with ob.phase("host_sync"):
-                jax.block_until_ready(self._caches)
-            with ob.phase("collective"):
-                nxt = np.asarray(nxt)
+        # with a mesh too: the collectives' device time is in the
+        # profiler trace, not in a host-clock wait
+        with ob.phase("host_sync"):
+            nxt = np.asarray(nxt)
         with ob.phase("sample_copy"):
             for s in decodable:
                 req = self._active[s]

@@ -336,7 +336,8 @@ def test_decode_stays_one_dispatch_per_step():
 def test_sharded_pool_layout_and_gauges():
     # the paged pool is physically head-parallel: each shard holds
     # (num_blocks, block_len, KH/tp, hd), and the mesh gauges + the
-    # per-step collective span land in the metrics snapshot
+    # per-step host_sync span (one wait on the sampled tokens, as without
+    # a mesh) land in the metrics snapshot
     cfg, params = _params_for("gqa")
     ob = repro_obs.Observability()
     _, eng = _serve(cfg, params, tp=2, kv_impl="paged", pai="pallas",
@@ -350,7 +351,8 @@ def test_sharded_pool_layout_and_gauges():
     assert shard_shapes == {pool.shape[:-2] + (kh // 2, pool.shape[-1])}
     assert ob.metrics.get("engine.mesh.tp").last == 2
     assert ob.metrics.get("engine.mesh.devices").last == _NDEV
-    assert ob.metrics.get("engine.phase.collective_ms").count > 0
+    assert ob.metrics.get("engine.phase.host_sync_ms").count > 0
+    assert ob.metrics.get("engine.phase.collective_ms") is None
 
 
 @multi_device

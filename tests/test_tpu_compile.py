@@ -10,11 +10,16 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 
+Each compile also checks the kernel's name: a Pallas kernel's ``name``
+becomes the instruction name of its custom call, which is how a
+profiler trace names it.
+
 The last test checks where the entry points put the persistent
 compilation cache (``repro.launch.compile_cache``).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +60,15 @@ def one_chip(topo):
     cc.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernel=None):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel is not None:
+        calls = re.findall(r"%(\S+?)(?:\.\d+)? = \S+ custom-call\(.*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert calls and set(calls) == {kernel}, calls
     return compiled
 
 
@@ -80,7 +90,7 @@ def test_gqa_decode_compiles(one_chip, softmax_impl, kv_quant):
             return paged_attention.gqa_decode(
                 q, kp, vp, t, kl, scale=HD ** -0.5, kv_quant=kv_quant,
                 k_scale_pool=ks, v_scale_pool=vs)
-    _compile(fn, one_chip, *shapes)
+    _compile(fn, one_chip, *shapes, kernel="gqa_decode")
 
 
 def test_mla_decode_compiles(one_chip):
@@ -88,17 +98,23 @@ def test_mla_decode_compiles(one_chip):
     fn = functools.partial(paged_attention.mla_decode, scale=(R + P) ** -0.5)
     _compile(fn, one_chip, ((B, H, R), jnp.float32), ((B, H, P), jnp.float32),
              ((N, L, R), jnp.float32), ((N, L, P), jnp.float32),
-             ((B, M), jnp.int32), ((B,), jnp.int32))
+             ((B, M), jnp.int32), ((B,), jnp.int32), kernel="mla_decode")
 
 
 def test_sigmoid_act_compiles(one_chip):
     fn = functools.partial(cordic_act.act_2d, op="sigmoid")
-    _compile(fn, one_chip, ((512, 1024), jnp.float32))
+    _compile(fn, one_chip, ((512, 1024), jnp.float32), kernel="cordic_act")
+
+
+def test_sigmoid_q_compiles(one_chip):
+    # the integer Q2.14 sigmoid: int32 codes in, int32 codes out
+    _compile(cordic_act.act_q_2d, one_chip, ((512, 1024), jnp.int32),
+             kernel="cordic_act_q")
 
 
 def test_silu_mul_bf16_compiles(one_chip):
     _compile(cordic_act.silu_mul_2d, one_chip, ((512, 1024), jnp.bfloat16),
-             ((512, 1024), jnp.bfloat16))
+             ((512, 1024), jnp.bfloat16), kernel="cordic_silu_mul")
 
 
 @pytest.mark.parametrize("name,cols", [("softmax_2d", 2048),
@@ -107,7 +123,7 @@ def test_row_softmax_compiles(one_chip, name, cols):
     # 64000 columns is Yi-9B's vocabulary under loss_impl="cordic_pallas":
     # its 8-row floor tile needs more than the default scoped VMEM limit
     _compile(getattr(softmax_cordic, name), one_chip,
-             ((256, cols), jnp.float32))
+             ((256, cols), jnp.float32), kernel="cordic_" + name[:-3])
 
 
 @pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax_cache"])
