@@ -160,8 +160,11 @@ IMPLS = {
 IMPL_KEYS = tuple(IMPLS)
 #: max_len values the transient working-set metric is recorded at; the
 #: pallas entry must be EQUAL at both (no max_len scaling), the gather
-#: entry must grow with max_len.
-TRANSIENT_MAX_LENS = (64, 128)
+#: entry must grow with max_len. The kernel holds a fixed chunk of up to
+#: 256 positions per pool, double-buffered, so the gather path's per-row
+#: buffers pass it only at longer contexts; 2048 is the served max_len of
+#: the on-chip benchmark's deployments.
+TRANSIENT_MAX_LENS = (2048, 4096)
 
 #: Smoke-mode tok/s baselines for this revision (idle dev host, CPU). The
 #: gate fails a metric below max(FLOOR_TOK_S, baseline * (1 - TOLERANCE))

@@ -93,8 +93,8 @@ class ModelConfig:
                                          # attends — full-table gather (dense-
                                          # shaped transient, provably bit-
                                          # identical) vs the block-walking
-                                         # Pallas kernel (O(block_len) VMEM
-                                         # transient per step, token-identical;
+                                         # Pallas kernel (one chunk of blocks
+                                         # in VMEM, token-identical;
                                          # kernels/paged_attention.py)
     kv_quant: str = "none"               # none | int8 | q2_14: paged-pool
                                          # storage format (core/kv_quant.py) —

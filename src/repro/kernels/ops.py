@@ -180,13 +180,13 @@ def paged_attend_gqa(q, k_pool, v_pool, tables, k_len, *, scale,
                      sched=PAPER_SCHEDULE, cfg=PAPER_FIXED) -> jax.Array:
     """Block-walking paged GQA decode attend (kernels/paged_attention.py).
 
-    Walks each row's live KV blocks through its block table — one block
-    in VMEM per grid step, online softmax in f32 scratch — instead of
-    gathering the full (max_len)-sized buffer.  Selected by
-    ``cfg.paged_attend_impl="pallas"`` in models.attention.  With
-    ``kv_quant`` set, the pools hold integer codes and the per-block
-    scale pools ride along: each block dequantizes in VMEM via the
-    CORDIC linear-rotation multiply (core/kv_quant.py).
+    Walks each row's live KV blocks through its block table — a
+    double-buffered chunk of blocks in VMEM at a time, online softmax in
+    f32 scratch — instead of gathering the full (max_len)-sized buffer.
+    Selected by ``cfg.paged_attend_impl="pallas"`` in models.attention.
+    With ``kv_quant`` set, the pools hold integer codes and the per-block
+    scales ride along: each chunk dequantizes in VMEM via the CORDIC
+    linear-rotation multiply (core/kv_quant.py).
     """
     from repro.kernels import paged_attention as PA
 

@@ -8,26 +8,42 @@ shape-identical to the dense path (which is what makes bit-identity
 provable), but the per-step transient working set still scales with
 ``max_len`` even when every live sequence is short.
 
-These kernels remove that transient: the grid runs over
-``slots x table-chunks`` and each grid step DMAs exactly ONE pool block
-into VMEM — the block id comes from the scalar-prefetched block table via
-the BlockSpec index map (``tables[slot, chunk]``), so the pipeline walks
-each row's *own* blocks in place and a ``max_len``-sized buffer is never
-materialized.  Softmax is accumulated online in f32 scratch that persists
-across the chunk axis (running max / running sum / weighted-value
-accumulator, flash-decoding style), and the per-row length mask is applied
-inside the loop; chunks at-or-past a row's live length skip their compute
-(their table entries point at the reserved scratch block 0).
+These kernels remove that transient and walk each row's *own* blocks in
+place, so a ``max_len``-sized buffer is never materialized:
+
+    gqa_decode walks only the live blocks. Its grid runs over the slots;
+    the pools stay in HBM, and a slot's program loops over its
+    ``ceil(k_len / (P*L))`` live chunks, each ``P`` consecutive table
+    entries (``chunk_blocks``: ``P`` follows from the pool's shapes, 16
+    blocks of 16 positions for Yi-9B and its tp=4 shard). A chunk is
+    fetched by one async copy per live table entry and pool (the block
+    ids come from the scalar-prefetched table) into a double-buffered
+    VMEM chunk: chunk c+1's copies fly while chunk c computes, and a
+    slot's last chunk starts the next slot's first, so DMA latency hides
+    across slots too. Entries and chunks past a slot's length cost
+    nothing: no copy, no compute. A vacant slot (all-zero table) walks
+    one block of scratch block 0. The time follows the live context, not
+    the table's length.
+
+    mla_decode keeps a ``slots x table-chunks`` grid that DMAs one pool
+    block per grid step through the BlockSpec index map
+    (``tables[slot, chunk]``); chunks at or past a row's length skip
+    their compute (their table entries point at scratch block 0).
+
+Softmax is accumulated online in f32 scratch (running max / running sum /
+weighted-value accumulator, flash-decoding style), with the per-row
+length mask applied to each chunk's scores.
 
 Selection (``cfg.paged_attend_impl``):
 
     "gather" — the PR-4 reference path: full-table gather, attend over
                dense shapes.  Transient = O(max_len) per row.  Exactly
                reproduces the dense path bit-for-bit.
-    "pallas" — these kernels.  Transient = O(block_len) per grid step,
-               independent of max_len.  The online-softmax accumulation
-               reorders float reductions, so attention *outputs* agree
-               with the gather path to f32 round-off (~1e-6 relative;
+    "pallas" — these kernels.  Transient = one chunk (GQA) or one block
+               (MLA) of K/V in VMEM, independent of max_len.  The
+               online-softmax accumulation reorders float reductions, so
+               attention *outputs* agree with the gather path to f32
+               round-off (~1e-6 relative;
                ~1e-3 for the Q2.14 CORDIC softmax) — and the emitted
                *tokens* are bit-identical, which is the serving contract
                and what the per-backend CI conformance suite enforces
@@ -37,8 +53,9 @@ The softmax follows ``softmax_impl``:
 
     "exact"          — one sweep over the live blocks with the classic
                        online-softmax rescaling recurrence (jnp.exp).
-    "cordic_fixed" / — three sweeps over the same blocks (a pass axis in
-    "cordic_pallas"    the grid; blocks stay O(block_len) in VMEM): exact
+    "cordic_fixed" / — three sweeps over the same blocks (MLA: a pass axis
+    "cordic_pallas"    in the grid; GQA: three walks of the slot's live
+                       chunks in its program; VMEM holds a chunk): exact
                        row max, then the CORDIC-exp row sum, then a
                        per-lane normalization that replicates the selected
                        backend's softmax *bit-for-bit* given (max, sum) —
@@ -63,7 +80,8 @@ Two kernels, one accumulation scheme:
                  mirroring models.attention._mla_absorbed_decode).
 
 CI exercises interpret mode (CPU); on TPU the same pallas_call compiles
-via Mosaic with the block table scalar-prefetched into SMEM.
+via Mosaic with the block table (and, for quantized pools, the slots'
+per-entry scales) scalar-prefetched into SMEM.
 """
 from __future__ import annotations
 
@@ -237,51 +255,162 @@ def _pass_update(s, v, pass_idx, impl, sched, cfg, m_sc, l_sc, acc_sc,
 # ---------------------------------------------------------------------------
 # GQA decode
 # ---------------------------------------------------------------------------
-def _gqa_kernel(tbl_ref, kl_ref, q_ref, k_ref, v_ref, *rest,
-                block_len: int, scale: float, impl: str, sched: MRSchedule,
-                cfg: FixedConfig, kv_dtype, kv_quant_spec=None):
-    # quantized pools add two scale refs between the pools and the output
-    if kv_quant_spec is not None:
-        ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = rest
+#: a walked chunk holds at most this many positions, and one chunk buffer
+#: of one pool at most this many bytes (see chunk_blocks)
+_CHUNK_POSITIONS = 256
+_CHUNK_BYTES = 512 * 1024
+
+
+def chunk_blocks(block_len: int, kv_heads: int, head_dim: int,
+                 itemsize: int) -> int:
+    """Pool blocks per chunk of gqa_decode's walk, from the pool's shapes:
+    as many as keep a chunk within ``_CHUNK_POSITIONS`` positions and one
+    chunk buffer of one pool within ``_CHUNK_BYTES``.  Yi-9B's f32 pool
+    (block_len 16, 4 KV heads, head_dim 128: 32 KiB a block) and its
+    2-KV-head tp=4 shard both walk 16 blocks, 256 positions, a chunk."""
+    block_bytes = block_len * kv_heads * head_dim * itemsize
+    return max(1, min(_CHUNK_POSITIONS // block_len,
+                      _CHUNK_BYTES // block_bytes))
+
+
+def _gqa_kernel(tbl_ref, kl_ref, *rest, block_len: int, chunk_blocks: int,
+                scale: float, impl: str, sched: MRSchedule, cfg: FixedConfig,
+                kv_dtype, kv_quant_spec=None):
+    # the pools stay in HBM; each has a double-buffered VMEM chunk and one
+    # DMA semaphore per buffer.  Quantized pools add the slots' per-entry
+    # scale tables (scalar-prefetched like the block table) and the
+    # dequantized chunks.
+    spec = kv_quant_spec
+    if spec is not None:
+        (ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
+         buf_idx, kdq, vdq, scale_col, m_sc, l_sc, acc_sc) = rest
     else:
-        o_ref, m_sc, l_sc, acc_sc = rest
-    b, p, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, buf_idx,
+         m_sc, l_sc, acc_sc) = rest
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    M = tbl_ref.shape[1]
+    KH = q_ref.shape[1]
+    L, P = block_len, chunk_blocks
+    RB = L * KH                         # chunk-buffer rows per pool block
+    CL = P * L                          # positions per chunk
+    n_chunks = -(-M // P)
 
-    @pl.when((p == 0) & (c == 0))
-    def _():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+    def live(row):
+        # (length, live table entries, live chunks); a length past the
+        # table reads as the table (a vacant slot's length keeps counting
+        # decode steps while its table stays on scratch block 0)
+        klen = jnp.minimum(kl_ref[row], M * L)
+        nblk = jax.lax.div(klen + L - 1, L)
+        return klen, nblk, jnp.clip(jax.lax.div(klen + CL - 1, CL), 1,
+                                    n_chunks)
 
-    klen = kl_ref[b]
-    base = c * block_len
+    def chunk_dma(row, c, nblk, buf, start: bool):
+        # one copy per live table entry of chunk c and pool; entries at or
+        # past the slot's live count move nothing (start and wait walk the
+        # same entries, so every copy started is waited on)
+        def entry(i, carry):
+            blk = tbl_ref[row, c * P + i] if start else 0
+            rows = pl.ds(pl.multiple_of(i * RB, RB), RB)
+            for j, (src, dst) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(src.at[blk], dst.at[buf, rows],
+                                           sems.at[j, buf])
+                if start:
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
 
-    @pl.when(base < klen)                       # dead chunks: skip compute
-    def _():
-        q = q_ref[0].astype(jnp.float32)                        # (KH,G,hd)
-        if kv_quant_spec is None:
-            k = k_ref[0].astype(kv_dtype).astype(jnp.float32)   # (L,KH,hd)
-            v = v_ref[0].astype(kv_dtype).astype(jnp.float32)
+        jax.lax.fori_loop(0, jnp.clip(nblk - c * P, 0, P), entry, 0)
+
+    def dequant(codes, scales, dst, buf, c):
+        # the kv_dtype cast seam as a real dequant stage: the chunk's
+        # integer codes x each row's block-and-head scale on the CORDIC
+        # linear-rotation multiply (elementwise on exactly the (code,
+        # scale) pairs the gather oracle dequantizes, so rounding
+        # matches), cast as the gather path casts
+        from repro.core import kv_quant as kvq
+
+        head = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (RB, 1), 0),
+                           KH)
+
+        def block_scales(i, carry):
+            e = jnp.minimum(c * P + i, M - 1)
+            sc = jnp.zeros((RB, 1), jnp.float32)
+            for h in range(KH):
+                sc = jnp.where(head == h, scales[b, e * KH + h], sc)
+            scale_col[pl.ds(pl.multiple_of(i * RB, RB), RB), :] = sc
+            return carry
+
+        jax.lax.fori_loop(0, P, block_scales, 0)
+        dst[...] = kvq.dequantize(codes[buf], spec, scale_col[...]).astype(
+            kv_dtype).astype(jnp.float32)
+
+    def attend(buf, c, p, klen):
+        if spec is None:
+            k_src, v_src = kbuf.at[buf], vbuf.at[buf]
         else:
-            # the kv_dtype cast seam as a real dequant stage: this block's
-            # integer codes x its per-head scale, on the CORDIC linear-
-            # rotation multiply — elementwise on exactly the (code, scale)
-            # pairs the gather oracle dequantizes, so rounding matches
-            from repro.core import kv_quant as kvq
+            dequant(kbuf, ks_ref, kdq, buf, c)
+            dequant(vbuf, vs_ref, vdq, buf, c)
+            k_src, v_src = kdq, vdq
+        for h in range(KH):
+            # head h's rows of the chunk: every KH-th buffer row
+            k = k_src[pl.ds(h, CL, stride=KH), :]
+            v = v_src[pl.ds(h, CL, stride=KH), :]
+            if spec is None:
+                k = k.astype(kv_dtype).astype(jnp.float32)
+                v = v.astype(kv_dtype).astype(jnp.float32)
+            q = q_ref[0, h].astype(jnp.float32)                  # (G, hd)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (G, CL)
+            pos = c * CL + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < klen, s, NEG_INF)
+            _pass_update(
+                s, v, p, impl, sched, cfg, m_sc.at[h], l_sc.at[h],
+                acc_sc.at[h],
+                lambda pr, vb: jnp.dot(pr, vb,
+                                       preferred_element_type=jnp.float32))
 
-            k = kvq.dequantize(k_ref[0], kv_quant_spec,
-                               ks_ref[0]).astype(kv_dtype).astype(jnp.float32)
-            v = kvq.dequantize(v_ref[0], kv_quant_spec,
-                               vs_ref[0]).astype(kv_dtype).astype(jnp.float32)
-        s = jnp.einsum("hgd,lhd->hgl", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < klen, s, NEG_INF)
-        _pass_update(
-            s, v, p, impl, sched, cfg, m_sc, l_sc, acc_sc,
-            lambda pr, vb: jnp.einsum("hgl,lhd->hgd", pr, vb,
-                                      preferred_element_type=jnp.float32))
+    klen, nblk, n_live = live(b)
 
+    @pl.when(b == 0)
+    def _():
+        # entries a chunk does not fetch keep what the buffer held: pool
+        # data of an earlier chunk, or these zeros, never uninitialized
+        # VMEM (masked lanes weigh 0, and 0 x NaN would not)
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        buf_idx[0] = 0
+        chunk_dma(b, 0, nblk, 0, start=True)
+
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    cur = buf_idx[0]
+    total = _num_passes(impl) * n_live
+
+    def step(j, carry):
+        # step j is pass j // n_live over chunk j % n_live; the copies of
+        # the next step (or of the next slot's first chunk) fly while this
+        # one computes
+        buf = jax.lax.rem(cur + j, 2)
+
+        @pl.when(j + 1 < total)
+        def _():
+            chunk_dma(b, jax.lax.rem(j + 1, n_live), nblk, 1 - buf,
+                      start=True)
+
+        @pl.when((j + 1 == total) & (b + 1 < nb))
+        def _():
+            chunk_dma(b + 1, 0, live(b + 1)[1], 1 - buf, start=True)
+
+        c = jax.lax.rem(j, n_live)
+        chunk_dma(b, c, nblk, buf, start=False)
+        attend(buf, c, jax.lax.div(j, n_live), klen)
+        return carry
+
+    jax.lax.fori_loop(0, total, step, 0)
+    buf_idx[0] = jax.lax.rem(cur + total, 2)
     if impl in (None, "exact"):
         o_ref[...] = (acc_sc[...] / l_sc[...])[None]
     else:
@@ -306,12 +435,12 @@ def gqa_decode(q, k_pool, v_pool, tables, k_len, *, scale: float,
     k_len:   (B,) int32 valid key count per row; must be >= 1 (the decode
              step writes its new element before attending)
     kv_dtype: storage dtype the gather path would cast K/V to (x.dtype in
-             models.attention) — applied per block so both paths attend
+             models.attention) — applied per chunk so both paths attend
              identically-rounded K/V.  Validated float (canonical_kv_dtype).
     kv_quant: "none" | "int8" | "q2_14" (core/kv_quant.py).  When set, the
              pools hold integer codes, ``k_scale_pool``/``v_scale_pool``
              carry the (N, 1, KH, 1) f32 per-block-per-head scales, and
-             each grid step dequantizes its block in VMEM via the CORDIC
+             each chunk is dequantized in VMEM via the CORDIC
              linear-rotation multiply before scoring.
 
     Returns (B, KH, G, hd) f32 attention outputs.
@@ -336,47 +465,50 @@ def gqa_decode(q, k_pool, v_pool, tables, k_len, *, scale: float,
         kv_dtype = (jnp.dtype(jnp.float32) if spec is not None
                     else canonical_kv_dtype(k_pool.dtype))
 
-    in_specs = [
-        pl.BlockSpec((1, KH, G, hd),
-                     lambda b, p, c, t, kl: (b, 0, 0, 0)),
-        pl.BlockSpec((1, L, KH, hd),
-                     lambda b, p, c, t, kl: (t[b, c], 0, 0, 0)),
-        pl.BlockSpec((1, L, KH, hd),
-                     lambda b, p, c, t, kl: (t[b, c], 0, 0, 0)),
-    ]
-    operands = (tables, k_len, q, k_pool, v_pool)
+    P = chunk_blocks(L, KH, hd, k_pool.dtype.itemsize)
+    R = P * L * KH
+    # (N, L, KH, hd) -> (N, L*KH, hd): position-major rows, head h of
+    # position l on row l*KH + h; the same bytes in HBM, so no copy
+    pools = (k_pool.reshape(N, L * KH, hd), v_pool.reshape(N, L * KH, hd))
+    prefetch = (tables, k_len)
+    scratch = [pltpu.VMEM((2, R, hd), k_pool.dtype),
+               pltpu.VMEM((2, R, hd), v_pool.dtype),
+               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((1,), jnp.int32)]         # buffer of the next chunk
     if spec is not None:
-        # per-block scales ride the same table walk as their code blocks
-        in_specs += [
-            pl.BlockSpec((1, 1, KH, 1),
-                         lambda b, p, c, t, kl: (t[b, c], 0, 0, 0)),
-            pl.BlockSpec((1, 1, KH, 1),
-                         lambda b, p, c, t, kl: (t[b, c], 0, 0, 0)),
-        ]
-        operands += (k_scale_pool, v_scale_pool)
-
+        # per slot, the scale of each table entry and head: (B, M*KH)
+        prefetch += tuple(sp.reshape(N, KH)[tables].reshape(B, M * KH)
+                          for sp in (k_scale_pool, v_scale_pool))
+        scratch += [pltpu.VMEM((R, hd), jnp.float32)] * 2   # dequantized
+        scratch += [pltpu.VMEM((R, 1), jnp.float32)]        # row scales
+    scratch += [
+        pltpu.VMEM((KH, G, 1), jnp.float32),    # running max
+        pltpu.VMEM((KH, G, 1), jnp.float32),    # running sum
+        pltpu.VMEM((KH, G, hd), jnp.float32),   # value accumulator
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, _num_passes(softmax_impl), M),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KH, G, hd),
-                               lambda b, p, c, t, kl: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((KH, G, 1), jnp.float32),    # running max
-            pltpu.VMEM((KH, G, 1), jnp.float32),    # running sum
-            pltpu.VMEM((KH, G, hd), jnp.float32),   # value accumulator
-        ],
+        num_scalar_prefetch=len(prefetch),
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, KH, G, hd), lambda b, *_: (b, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, KH, G, hd), lambda b, *_: (b, 0, 0, 0)),
+        scratch_shapes=scratch,
     )
-    kern = functools.partial(_gqa_kernel, block_len=L, scale=float(scale),
-                             impl=softmax_impl, sched=sched, cfg=cfg,
-                             kv_dtype=kv_dtype, kv_quant_spec=spec)
+    kern = functools.partial(_gqa_kernel, block_len=L, chunk_blocks=P,
+                             scale=float(scale), impl=softmax_impl,
+                             sched=sched, cfg=cfg, kv_dtype=kv_dtype,
+                             kv_quant_spec=spec)
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), jnp.float32),
+        # a slot's last step starts the next slot's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="gqa_decode",
-    )(*operands)
+    )(*prefetch, q, *pools)
 
 
 # ---------------------------------------------------------------------------
@@ -474,8 +606,8 @@ def mla_decode(q_eff, q_rope, c_pool, r_pool, tables, k_len, *, scale: float,
 # pallas_call is opaque to the GSPMD partitioner — XLA cannot slice a
 # kernel's grid or its scalar-prefetched block tables, so running these
 # kernels on a sharded cache means wrapping them in shard_map over the
-# model axis: each shard runs the SAME grid (slots x passes x table
-# chunks) against its OWN head slice of the pools, with the block tables
+# model axis: each shard runs the SAME walk over the same table entries
+# against its OWN head slice of the pools, with the block tables
 # and lengths replicated (they are head-invariant host metadata — the
 # whole point of KVPager staying shard-agnostic). The per-shard kernel is
 # bitwise the single-device kernel on a narrower head axis, and head
@@ -558,17 +690,21 @@ def decode_transient_bytes(cfg, *, max_len: int, block_len: int,
 
     "gather" materializes the full table gather — two (max_len, heads,
     dim)-shaped buffers per row (K and V, or latent + rope for MLA) — so
-    it scales with ``max_len``.  "pallas" holds exactly the VMEM blocks of
-    one grid step (q + one K/V block pair + the f32 scratch accumulators):
-    a function of ``block_len`` only.  Derived from the same shapes the
-    BlockSpecs above are built from, so this metric cannot drift from the
-    kernel silently.
+    it scales with ``max_len``.  "pallas" holds the kernel's VMEM: for
+    MLA one grid step's blocks (q + one latent/rope block pair + the f32
+    scratch accumulators), for GQA q, the double-buffered K and V chunk
+    of ``chunk_blocks`` blocks and the f32 scratch — a function of
+    ``block_len`` and the head geometry only.  Derived from the same
+    shapes the kernels are built from, so this metric cannot drift from
+    them silently.
 
     kv_quant != "none" (GQA only): gathered/streamed K/V are integer codes
     in the format's lane width plus per-block f32 scales, and every read
     also materializes the dequantized f32 buffer — the transient trades a
     narrower gather for the dequant copy; the *resident* pool is where
-    quantization wins (kv.quant.bytes_per_token).
+    quantization wins (kv.quant.bytes_per_token).  The kernel's per-entry
+    scale tables are scalar-prefetched metadata, like the block table,
+    and not counted.
     """
     from repro.core import kv_quant as kvq
 
@@ -600,10 +736,11 @@ def decode_transient_bytes(cfg, *, max_len: int, block_len: int,
             dequant = 2 * max_len * KH * hd * 4
             return codes + scales + dequant
         if impl == "pallas":
+            rows = chunk_blocks(block_len, KH, hd, kv_ib) * block_len * KH
             q = KH * G * hd * ib
-            kv = 2 * block_len * KH * hd * kv_ib
+            kv = 2 * 2 * rows * hd * kv_ib          # K and V, two buffers
             scratch = (KH * G * 2 + KH * G * hd) * 4
-            extra = (2 * KH * 4 + 2 * block_len * KH * hd * 4
+            extra = ((2 * rows * hd + rows) * 4     # dequantized, row scales
                      if spec is not None else 0)
             return q + kv + KH * G * hd * 4 + scratch + extra
     raise ValueError(f"unknown paged_attend_impl {impl!r}")

@@ -48,7 +48,8 @@ def main(argv=None):
                     choices=["gather", "pallas"],
                     help="paged decode attend: full-table gather (dense-"
                          "shaped transient) or the block-walking Pallas "
-                         "kernel (O(block_len) transient; same tokens). "
+                         "kernel (transient independent of max_len; same "
+                         "tokens). "
                          "Requires --kv-impl paged")
     ap.add_argument("--kv-quant", default="none",
                     choices=["none", "int8", "q2_14"],

@@ -124,9 +124,10 @@ def _paged_attend_impl(cfg) -> str:
                (_pool_gather + _attend_rows / _mla_absorbed_decode): the
                provably bit-identical reference.
     "pallas" — walk the block table in place with the block-walking decode
-               kernel (kernels/paged_attention.py): O(block_len) transient
-               instead of O(max_len); emitted tokens identical (enforced
-               per backend in tests/test_paged_attention.py).  Applies to
+               kernel (kernels/paged_attention.py): a transient of one
+               chunk of blocks instead of O(max_len); emitted tokens
+               identical (enforced per backend in
+               tests/test_paged_attention.py).  Applies to
                the single-query decode step only — paged *prefill* always
                takes the gather path.
     """
@@ -247,7 +248,7 @@ def _pool_gather(pool, tables):
     bit-identical to dense. Paging therefore shrinks *resident* KV (the
     pool) but not the per-step gather; ``cfg.paged_attend_impl="pallas"``
     swaps the decode step for the block-walking kernel in
-    kernels/paged_attention.py, whose transient is O(block_len) instead."""
+    kernels/paged_attention.py, whose transient does not grow with max_len."""
     B, M = tables.shape
     L = pool.shape[1]
     return pool[tables].reshape((B, M * L) + pool.shape[2:])

@@ -768,6 +768,12 @@ class ServeEngine:
         self._m_e2e = m.histogram("engine.e2e_ms", unit="ms")
         self._m_prefill = m.histogram("engine.prefill_ms", unit="ms")
         self._m_step = m.histogram("engine.step_ms", unit="ms")
+        # table entries the paged decode attend walks per dispatch: the
+        # live blocks of every decodable slot (the kernel walks nothing
+        # past a slot's length); against slots x max_blocks it is the
+        # share of the block tables that holds live context
+        self._m_live_blocks = m.histogram("engine.decode.live_blocks",
+                                          unit="blocks")
         self._m_compiles = {
             "prefill": m.counter("engine.compiles.prefill", unit="compiles"),
             "decode": m.counter("engine.compiles.decode", unit="compiles"),
@@ -1279,6 +1285,13 @@ class ServeEngine:
                 self._m_step.observe((time.perf_counter() - t_step) * 1e3)
                 self._obs_compiles()
             return n_rows
+        if ob.enabled and self.pager is not None:
+            # each decodable slot attends its prompt and every token so
+            # far, the one this dispatch feeds included
+            self._m_live_blocks.observe(sum(
+                kvp.blocks_needed(len(self._active[s].prompt)
+                                  + len(self._active[s].out), self.block_len)
+                for s in decodable))
         # phase spans: dispatch ends when jax hands back async futures,
         # host_sync is the device->host block on the sampled tokens,
         # sample_copy is pure host bookkeeping over the decodable slots

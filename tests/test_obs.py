@@ -371,6 +371,55 @@ def test_engine_metrics_populated():
         assert m.get(f"engine.phase.{ph}_ms").count >= steps
 
 
+def _device_lens(caches):
+    """(slots,) lengths of the first attention layer's paged cache."""
+    for path, leaf in jax.tree_util.tree_flatten_with_path(caches)[0]:
+        if getattr(path[-1], "key", None) == "lens":
+            return np.asarray(leaf).reshape(-1, np.asarray(leaf).shape[-1])[0]
+    raise AssertionError("no lens leaf in the paged cache")
+
+
+@pytest.mark.parametrize("observed", [True, False])
+def test_engine_decode_live_blocks(observed):
+    """engine.decode.live_blocks records, once per decode dispatch, the
+    live table entries sum(ceil(k_len / block_len)) of the decodable
+    slots, k_len being the length the attend sees on the device (the
+    cache's lens + 1); with observability off nothing is registered."""
+    cfg = _cfg()
+    params = tf.init(cfg, jax.random.PRNGKey(6))
+    ob = obs_lib.Observability() if observed else None
+    eng = ServeEngine(cfg, params, slots=3, max_len=64, kv_impl="paged",
+                      obs=ob)
+    L = eng.block_len
+    want = []
+    decode = eng._decode
+
+    def spy(params, caches, *rest):
+        lens = _device_lens(caches)
+        want.append(sum(-(-(int(lens[s]) + 1) // L)
+                        for s in range(eng.slots)
+                        if eng._active[s] is not None
+                        and s not in eng.scheduler.chunking))
+        return decode(params, caches, *rest)
+
+    eng._decode = spy
+    rng = np.random.default_rng(6)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n
+                                               ).astype(np.int32),
+                    max_new_tokens=m)
+            for i, (n, m) in enumerate([(15, 4), (17, 9), (31, 6), (5, 3)])]
+    _serve(eng, reqs)
+    assert len(want) > 3 and max(want) > min(want)
+    if not observed:
+        assert eng.obs.metrics.get("engine.decode.live_blocks") is None
+        return
+    h = ob.metrics.get("engine.decode.live_blocks")
+    assert h.unit == "blocks"
+    assert h.count == len(want)
+    assert h.sum == sum(want)
+    assert (h.min, h.max) == (min(want), max(want))
+
+
 def test_pager_backpressure_metric():
     ob = obs_lib.Observability()
     pager = kvp.KVPager(4, 16, 2, metrics=ob.metrics)

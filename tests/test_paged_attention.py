@@ -90,10 +90,19 @@ def _assert_kernel_matches_ref(q, kp, vp, tables, k_len, scale=0.3):
     assert np.isfinite(got).all()
 
 
-@pytest.mark.parametrize("klen", [1, 3, 4, 5, 7, 8, 9, 16])
+#: positions per chunk of the kernel's walk at _gqa_case's default
+#: geometry (L 4, KH 2, hd 8, f32): 64 blocks, 256 positions
+_CL = PA.chunk_blocks(4, 2, 8, 4) * 4
+
+
+@pytest.mark.parametrize("klen", [1, 3, 4, 5, 7, 8, 9, 16,
+                                  _CL - 1, _CL, _CL + 1, _CL + 4])
 def test_gqa_kernel_block_boundary_lengths(klen):
     """Lengths exactly on (4, 8, 16) and one off (3, 5, 7, 9) the L=4
-    block boundaries, plus the single-element and single-block cases."""
+    block boundaries, plus the single-element and single-block cases;
+    then one off and on the chunk boundary (_CL - 1, _CL, _CL + 1), and
+    a full table whose block count is not a multiple of the chunk's
+    (_CL + 4: 65 blocks, 64 a chunk)."""
     _assert_kernel_matches_ref(*_gqa_case([klen], seed=klen))
 
 
@@ -101,16 +110,30 @@ def test_gqa_kernel_single_block_slot():
     _assert_kernel_matches_ref(*_gqa_case([2], L=16))
 
 
-def test_gqa_kernel_mixed_length_batch():
-    """Rows at different lengths (spanning 1..4 live blocks) in one call."""
-    _assert_kernel_matches_ref(*_gqa_case([1, 4, 5, 13, 16, 3], seed=3))
+@pytest.mark.parametrize("klens", [[1, 4, 5, 13, 16, 3],
+                                   [_CL + 4, 4, 4, 4]],
+                         ids=["one_to_four_blocks",
+                              "full_table_beside_one_block"])
+def test_gqa_kernel_mixed_length_batch(klens):
+    """Rows at different lengths (spanning 1..4 live blocks) in one call;
+    and one row at the full table (two chunks, the second one block)
+    beside rows of one block each."""
+    _assert_kernel_matches_ref(*_gqa_case(klens, seed=3))
 
 
-def test_gqa_kernel_vacant_slot_reads_scratch():
+@pytest.mark.parametrize("klens,vacant_len", [([5, 0, 9], 1),
+                                              ([_CL + 1, 0, 5], 1),
+                                              ([_CL + 1, 0, 5], 10_000)],
+                         ids=["beside_short_rows", "beside_two_chunks",
+                              "length_past_table"])
+def test_gqa_kernel_vacant_slot_reads_scratch(klens, vacant_len):
     """A vacant row (all-zero table, len 0 -> k_len 1) rides along like an
     inactive engine slot: its output is finite garbage from scratch block
-    0, and the live rows are bit-unaffected by its presence."""
-    q, kp, vp, tables, k_len = _gqa_case([5, 0, 9], seed=4)
+    0, and the live rows are bit-unaffected by its presence.  The engine
+    keeps counting a vacant slot's length each decode step, so a length
+    past the whole table must read as the table."""
+    q, kp, vp, tables, k_len = _gqa_case(klens, seed=4)
+    k_len = k_len.at[1].set(vacant_len)
     assert int(tables[1].max()) == 0            # vacant -> scratch only
     _assert_kernel_matches_ref(q, kp, vp, tables, k_len)
     # live rows identical with the vacant row removed from the batch

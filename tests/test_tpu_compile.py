@@ -72,25 +72,39 @@ def _compile(fn, sharding, *shapes, kernel=None):
     return compiled
 
 
-@pytest.mark.parametrize("softmax_impl,kv_quant", [
-    ("exact", "none"), ("cordic_pallas", "none"), ("exact", "int8")])
-def test_gqa_decode_compiles(one_chip, softmax_impl, kv_quant):
+#: DeepSeek-Coder-33B's tp=4 shard: 2 of 8 KV heads a chip, 7 query
+#: groups, 16 slots, 128 table entries (max_len 2048)
+TP4 = dict(B=16, KH=2, G=7, M=128)
+
+
+@pytest.mark.parametrize("softmax_impl,kv_quant,geom", [
+    pytest.param("exact", "none", {}, id="exact-none"),
+    pytest.param("cordic_pallas", "none", {}, id="cordic_pallas-none"),
+    pytest.param("exact", "int8", {}, id="exact-int8"),
+    pytest.param("exact", "none", TP4, id="exact-none-tp4_shard")])
+def test_gqa_decode_compiles(one_chip, softmax_impl, kv_quant, geom):
+    b, kh, g, m = (geom.get(k, d) for k, d in
+                   (("B", B), ("KH", KH), ("G", G), ("M", M)))
+    n = b * m + 1
     pool_dtype = jnp.int8 if kv_quant == "int8" else jnp.float32
-    shapes = [((B, KH, G, HD), jnp.bfloat16),
-              ((N, L, KH, HD), pool_dtype), ((N, L, KH, HD), pool_dtype),
-              ((B, M), jnp.int32), ((B,), jnp.int32)]
+    shapes = [((b, kh, g, HD), jnp.bfloat16),
+              ((n, L, kh, HD), pool_dtype), ((n, L, kh, HD), pool_dtype),
+              ((b, m), jnp.int32), ((b,), jnp.int32)]
     if kv_quant == "none":
         fn = functools.partial(paged_attention.gqa_decode, scale=HD ** -0.5,
                                softmax_impl=softmax_impl,
                                kv_dtype=jnp.bfloat16)
     else:
-        shapes += [((N, 1, KH, 1), jnp.float32)] * 2
+        shapes += [((n, 1, kh, 1), jnp.float32)] * 2
 
         def fn(q, kp, vp, t, kl, ks, vs):
             return paged_attention.gqa_decode(
                 q, kp, vp, t, kl, scale=HD ** -0.5, kv_quant=kv_quant,
                 k_scale_pool=ks, v_scale_pool=vs)
-    _compile(fn, one_chip, *shapes, kernel="gqa_decode")
+    text = _compile(fn, one_chip, *shapes, kernel="gqa_decode").as_text()
+    # the benchmark's roofline reader finds the kernel by this result
+    assert re.search(rf"%gqa_decode(\.\d+)? = f32\[{b},{kh},{g},{HD}\]",
+                     text)
 
 
 def test_mla_decode_compiles(one_chip):
