@@ -4,10 +4,12 @@ window, and program and kernel time from the trace.
 Each reader in ``bench/metrics/`` gets the run's context: ``drv`` (the
 load driver, with its per-request and per-step records), ``t_open`` and
 ``t_close`` (the window on the host clock), ``m`` (the configuration's
-sizes), ``tp``, ``chips``, ``peaks``, and in a traced run ``trace`` (see
-trace_reduce.py), ``planes`` (the device planes of the chips in use) and
-``obs`` (the engine's observability handle, attached at the window's
-start). A reader returns None when it finds nothing to read.
+sizes), ``tp``, ``chips``, ``peaks``, ``costs`` (the configuration's
+counts module, bench/costs.py for the GQA configurations), and in a
+traced run ``trace`` (see trace_reduce.py), ``planes`` (the device planes
+of the chips in use) and ``obs`` (the engine's observability handle,
+attached at the window's start). A reader returns None when it finds
+nothing to read.
 """
 from __future__ import annotations
 
@@ -63,9 +65,9 @@ def idle_share(ctx) -> Optional[float]:
 
 def mfu(ctx) -> Optional[float]:
     """Model FLOPs of every token processed in the window, prompt and
-    output (bench/costs.py), over window x chips x bf16 peak, in %."""
-    import costs
-
+    output (the configuration's counts module, ``ctx.costs``), over
+    window x chips x bf16 peak, in %."""
+    costs = ctx.costs
     flops = 0.0
     for s in steps(ctx):
         flops += sum(costs.token_flops(ctx.m, c, lm_head=True) for c in s.ctx)

@@ -6,15 +6,22 @@ A cell names a configuration (``bench/configs/<config>.json``: sizes as
 run, the engine settings of the deployment, source and cuts) and a traffic mix
 (``bench/traffic/<mix>.json``, read by ``bench/loadgen.py``). Each metric
 has a reader of its own, ``bench/metrics/<metric>.py``; the limits of the
-correctness check are in ``bench/limits/<cell>.json``. Nothing here names
-a cell, a configuration or a metric: a new one is new files and entries.
+correctness check are in ``bench/limits/<cell>.json``. The configuration
+names, by path under ``bench/``, the three modules that know the model's
+shape (bench/weights.py says what each provides): its ``layout`` (the
+program's ModelConfig and the seeded weights), its plain ``reference`` and
+its ``costs`` (the model's operations). Nothing here names a cell, a
+configuration, a metric or a model family: a new one is new files and
+entries.
 
 A run:
   1. checks that JAX sees TPUs, as many as the cell asks for, and exits
      non-zero with no result otherwise;
   2. places JAX's persistent compilation cache at ``<checkout>/.jax_cache``;
-  3. draws the weights on the device from ``--seed`` in one jitted call
-     (bench/weights.py), builds ``repro.serve.engine.ServeEngine``, and
+  3. loads the configuration's layout, reference and costs modules, checks
+     that the layout's parameter tree is the program's own, draws the
+     weights on the device from ``--seed`` in one jitted call (the
+     layout's ``program_tree``), builds ``repro.serve.engine.ServeEngine``, and
      serves a fixed set of warm-up requests that compiles every prefill
      and decode shape the cell's traffic uses, and no other;
   4. queues the backlog, or starts the open-loop arrivals a few seconds
@@ -26,12 +33,13 @@ A run:
      and a breakdown; with ``--trace 0`` reports the end-to-end metrics;
   6. reads the peak device memory, frees the program's weights and caches,
      and checks a seeded sample of the finished requests, the longest among
-     them, against the plain float32 reference (bench/reference.py): the
-     widest gap by which a served token's reference logit lies below the
+     them, against the configuration's plain reference (its ``reference``
+     module, given the layout's ``Source`` of the same weights): the widest
+     gap by which a served token's reference logit lies below the
      reference's best must stay within the cell's limit. With
-     ``control=True`` (bench/control.py) the float8 control takes the
-     program's place in that comparison: the tokens it puts first at the
-     same positions are compared, against the same limit.
+     ``control=True`` (bench/control.py) the reference's lower-precision
+     control takes the program's place in that comparison: the tokens it
+     puts first at the same positions are compared, against the same limit.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -52,6 +60,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
@@ -66,8 +75,9 @@ WORK_DIR = os.path.join(ROOT, ".bench_work")
 
 
 class RunFailed(Exception):
-    """The run cannot report a result (no chip, a compile in the window,
-    a metric the cell must report that could not be read)."""
+    """The run cannot report a result (no chip, a configuration without
+    its model modules, a compile in the window, a metric the cell must
+    report that could not be read)."""
 
 
 def log(msg: str) -> None:
@@ -102,13 +112,55 @@ def resolve(cell_name: str) -> dict:
             "end_to_end": e2e, "per_layer": layer}
 
 
+#: the modules a configuration names, and what each has to provide
+ROLES = {"layout": ("model_config", "abstract_tree", "program_tree",
+                    "Source"),
+         "reference": ("widest_gap",),
+         "costs": ("token_flops", "prompt_flops")}
+
+
+def load_module(path: str):
+    """The module at ``path`` (a file under bench/, relative to the
+    checkout), executed once per process."""
+    full = os.path.realpath(os.path.join(ROOT, path))
+    name = "bench_" + re.sub(r"\W", "_", os.path.relpath(full, BENCH))
+    if name not in sys.modules:
+        mod_spec = importlib.util.spec_from_file_location(name, full)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[name] = mod
+        try:
+            mod_spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def reader(name: str):
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(os.path.join("bench", "metrics", name + ".py")).read
+
+
+def model_modules(config: dict) -> types.SimpleNamespace:
+    """The ``layout``, ``reference`` and ``costs`` modules the
+    configuration names (paths under bench/, see bench/weights.py)."""
+    bench = os.path.realpath(BENCH)
+    mods = {}
+    for role, needs in ROLES.items():
+        path = config.get(role)
+        if not path:
+            raise RunFailed(f"configuration {config.get('name')!r} names no "
+                            f"{role!r} module")
+        full = os.path.realpath(os.path.join(ROOT, path))
+        if (os.path.commonpath([full, bench]) != bench
+                or not os.path.isfile(full)):
+            raise RunFailed(f"the {role!r} module {path!r} is no file under "
+                            f"bench/")
+        mod = load_module(path)
+        missing = [f for f in needs if not hasattr(mod, f)]
+        if missing:
+            raise RunFailed(f"the {role!r} module {path!r} lacks {missing}")
+        mods[role] = mod
+    return types.SimpleNamespace(**mods)
 
 
 def tpu_devices(chips: int):
@@ -148,31 +200,14 @@ class CompileWatch:
             self.events += 1
 
 
-def model_config(m: dict, engine: dict):
-    """The program's ModelConfig for a configuration's ``model`` block."""
-    from repro.configs.base import ModelConfig
-
-    return ModelConfig(
-        name="bench", family="dense", num_layers=m["num_layers"],
-        d_model=m["d_model"], num_heads=m["num_heads"],
-        num_kv_heads=m["num_kv_heads"], head_dim=m["head_dim"],
-        d_ff=m["d_ff"], vocab_size=m["vocab_size"],
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
-        act_impl=m["act_impl"], softmax_impl=m["softmax_impl"],
-        dtype=m["dtype"], qkv_bias=False, tie_embeddings=False,
-        kv_impl="paged", kv_block_len=engine["block_len"],
-        paged_attend_impl=engine["paged_attend_impl"])
-
-
-def check_layout(cfg, m: dict, dtype) -> None:
-    """The weights bench/weights.py lays out must be the program's tree."""
+def check_layout(layout, cfg, m: dict, dtype) -> None:
+    """The weights the layout module lays out must be the program's tree."""
     import jax
 
     from repro.models import transformer as tf
-    import weights
 
     want = jax.eval_shape(lambda: tf.init(cfg, jax.random.PRNGKey(0), dtype))
-    got = weights.abstract_tree(m, dtype)
+    got = layout.abstract_tree(m, dtype)
     flat = lambda t: {jax.tree_util.keystr(p): (tuple(x.shape), str(x.dtype))
                       for p, x in jax.tree_util.tree_leaves_with_path(t)}
     if flat(want) != flat(got):
@@ -261,8 +296,8 @@ def run(argv=None, *, require_tpu: bool = True, override=None, fault=None,
         control: bool = False, check: bool = True):
     """One benchmark run; returns the result object (raises RunFailed).
 
-    ``control=True`` puts the float8 control in the program's place in the
-    comparison that decides ``correct`` (bench/control.py); the program's
+    ``control=True`` puts the reference's control in the program's place in
+    the comparison that decides ``correct`` (bench/control.py); the program's
     own reading is kept beside it. ``check=False`` leaves the reference out
     (bench/sweep.py, which reads load only). ``require_tpu=False``,
     ``override`` (a dict that replaces parts of the resolved cell) and
@@ -290,11 +325,10 @@ def run(argv=None, *, require_tpu: bool = True, override=None, fault=None,
     import numpy as np
 
     devs = tpu_devices(chips) if require_tpu else jax.devices()
+    mods = model_modules(config)
     import loadgen
     import peaks
-    import reference
     import trace_reduce
-    import weights
     from repro.launch import compile_cache
     from repro.launch import mesh as mesh_lib
     from repro.distributed import sharding as shd
@@ -307,12 +341,12 @@ def run(argv=None, *, require_tpu: bool = True, override=None, fault=None,
     t = {"start": T_START, "jax": time.perf_counter()}
 
     dtype = jnp.dtype(m["dtype"])
-    cfg = model_config(m, eng_spec)
-    check_layout(cfg, m, dtype)
+    cfg = mods.layout.model_config(m, eng_spec)
+    check_layout(mods.layout, cfg, m, dtype)
     mesh = mesh_lib.mesh_or_none(tp)
     shardings = (None if mesh is None else shd.serve_param_shardings(
-        cfg, weights.abstract_tree(m, dtype), mesh))
-    params = weights.program_tree(m, args.seed, dtype, shardings)
+        cfg, mods.layout.abstract_tree(m, dtype), mesh))
+    params = mods.layout.program_tree(m, args.seed, dtype, shardings)
     jax.block_until_ready(params)
     t["weights"] = time.perf_counter()
     eng = ServeEngine(
@@ -397,8 +431,9 @@ def run(argv=None, *, require_tpu: bool = True, override=None, fault=None,
 
     ctx = types.SimpleNamespace(
         args=args, cell=cell, config=config, m=m, traffic=traffic, tp=tp,
-        chips=chips, peaks=pk, drv=drv, t_open=t_open, t_close=t_close,
-        setup_s=setup_s, obs=obs, trace=None, planes=None, log=log)
+        chips=chips, peaks=pk, costs=mods.costs, drv=drv, t_open=t_open,
+        t_close=t_close, setup_s=setup_s, obs=obs, trace=None, planes=None,
+        log=log)
     result_device = {"platform": devs[0].platform, "kind": kind,
                      "count": len(devs),
                      "memory_peak_bytes": max((b for b in mem if b), default=0)}
@@ -455,8 +490,8 @@ def run(argv=None, *, require_tpu: bool = True, override=None, fault=None,
                                      for x in tr.req.out))
     program_gap = None
     if sample and check:
-        src = weights.Source(m, args.seed, dtype)
-        program_gap, control_gap, n_tok = reference.widest_gap(
+        src = mods.layout.Source(m, args.seed, dtype)
+        program_gap, control_gap, n_tok = mods.reference.widest_gap(
             src, m, [(tr.req.prompt, list(tr.req.out)) for tr in sample],
             control=control)
         gap = control_gap if control else program_gap

@@ -4,10 +4,12 @@ control put in the program's place, is not.
 
 The faults a served-model cell can have: a decode step that hands back
 its cache unchanged, and a token altered where the step produces it.
-(Leaving half a training batch out has no counterpart in serving; the
-chip-to-chip exchange exists only in a four-chip cell, which this
-benchmark does not have.)
+(Leaving half a training batch out has no counterpart in serving.) The
+four-chip cell (``dscoder33b.tp4.decode_batch``) can also lose the
+exchange between chips; its tests run the same path at tp=4 on the four
+CPU devices that conftest.py asks for.
 """
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -28,15 +30,25 @@ TRAFFIC = {"arrival": {"kind": "backlog", "requests": 16},
 LIMIT = 0.05
 
 
-def _run(seed=3, fault=None, control=False):
-    r = bench_run.resolve("yi9b.decode_batch")
-    r["config"] = dict(r["config"], model=MODEL, engine=ENGINE)
+#: the tp=4 cell's shape at the same size: heads and d_ff divide by 4
+MODEL_TP4 = dict(MODEL, num_heads=8, num_kv_heads=4, head_dim=16)
+
+
+def _run(seed=3, fault=None, control=False, cell="yi9b.decode_batch",
+         model=MODEL, tp=1):
+    r = bench_run.resolve(cell)
+    r["config"] = dict(r["config"], model=model, engine=dict(ENGINE, tp=tp))
     r["traffic"] = TRAFFIC
     r["limits"] = {"widest_logit_gap": {"limit": LIMIT}}
-    return bench_run.run(["--workload", "yi9b.decode_batch", "--seed",
+    return bench_run.run(["--workload", cell, "--seed",
                           str(seed), "--seconds", "2", "--trace", "0"],
                          require_tpu=False, override=r, fault=fault,
                          control=control)
+
+
+def _run_tp4(fault=None):
+    return _run(fault=fault, cell="dscoder33b.tp4.decode_batch",
+                model=MODEL_TP4, tp=4)
 
 
 def _wrap_decode(eng, change):
@@ -75,6 +87,30 @@ def test_float8_control_is_not_correct():
     ctrl = out["compared"]["widest_logit_gap"]["value"]
     prog = out["program_widest_logit_gap"]
     assert prog <= LIMIT < ctrl and ctrl > 3 * prog, (prog, ctrl)
+
+
+def test_sound_tp4_run_is_correct():
+    out = _run_tp4()
+    assert out["correct"], out["compared"]
+    assert out["compared"]["tokens_checked"]["value"] > 20
+
+
+def test_exchange_between_chips_left_out_is_not_correct():
+    # without the all-reduce after each row-parallel product, a chip sums
+    # only its own heads' attention output and its own slice of d_ff: as
+    # the first chip sees it, the other chips' rows of wo and w_down are 0
+    def drop_exchange(eng):
+        h = MODEL_TP4["num_heads"] // 4
+        f = MODEL_TP4["d_ff"] // 4
+        seg = eng.params["seg0"]
+        part = {**eng.params, "seg0": {
+            **seg,
+            "attn": {**seg["attn"], "wo": seg["attn"]["wo"].at[:, h:].set(0)},
+            "mlp": {**seg["mlp"],
+                    "w_down": seg["mlp"]["w_down"].at[:, f:].set(0)}}}
+        eng.params = jax.device_put(part, eng._param_sh)
+    out = _run_tp4(fault=drop_exchange)
+    assert not out["correct"], out["compared"]
 
 
 def test_no_chip_no_result(capsys):

@@ -19,7 +19,7 @@ ENGINE = {"block_len": 16, "paged_attend_impl": "gather"}
 def test_reference_matches_the_program_forward_pass():
     from repro.models import transformer as tf
 
-    cfg = bench_run.model_config(SMALL, ENGINE)
+    cfg = weights.model_config(SMALL, ENGINE)
     params = weights.program_tree(SMALL, 5, jnp.float32)
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, 128, n).astype(np.int32) for n in (37, 20)]
@@ -50,8 +50,8 @@ def test_layer_source_draws_the_program_weights_again():
 
 
 def test_layout_matches_the_program_tree():
-    cfg = bench_run.model_config(SMALL, ENGINE)
-    bench_run.check_layout(cfg, SMALL, jnp.float32)
+    cfg = weights.model_config(SMALL, ENGINE)
+    bench_run.check_layout(weights, cfg, SMALL, jnp.float32)
 
 
 def test_gap_is_zero_for_the_reference_argmax():

@@ -1,4 +1,22 @@
-"""Seeded weights of a dense GQA decoder, made by the benchmark.
+"""Layout of a dense GQA decoder (Yi, DeepSeek-Coder): the program's model
+configuration and its seeded weights, made by the benchmark.
+
+A configuration (``bench/configs/<config>.json``) names three modules by
+path, and ``bench/run.py`` loads them and nothing model-specific besides:
+
+  ``layout``     ``model_config(m, engine)``, the program's ``ModelConfig``
+                 for the configuration's ``model`` block, and its weights:
+                 ``abstract_tree(m, dtype)``, ``program_tree(m, seed, dtype,
+                 shardings)`` and ``Source(m, seed, dtype)``;
+  ``reference``  ``widest_gap(source, m, served, control=)``, the plain
+                 reference that decides ``correct`` (bench/reference.py);
+  ``costs``      ``token_flops(m, ctx, lm_head)`` and ``prompt_flops(m,
+                 plen)``, the model's operations for ``mfu`` (bench/costs.py).
+
+This module is the layout of the GQA configurations. A model of another
+shape joins the benchmark as new files: its configuration, a layout, a
+reference and a counts module of its own, and its own roofline readers in
+``bench/metrics/``.
 
 The benchmark draws the weights itself, so that the reference can draw
 the same ones again, layer by layer, after the program's copy is freed:
@@ -29,6 +47,22 @@ import numpy as np
 
 #: (path, per-layer shape, stacked over layers, kind, fan-in) per leaf
 Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], bool, str, Optional[int]]
+
+
+def model_config(m: dict, engine: dict):
+    """The program's ModelConfig for a configuration's ``model`` block."""
+    from repro.configs.base import ModelConfig
+
+    return ModelConfig(
+        name="bench", family="dense", num_layers=m["num_layers"],
+        d_model=m["d_model"], num_heads=m["num_heads"],
+        num_kv_heads=m["num_kv_heads"], head_dim=m["head_dim"],
+        d_ff=m["d_ff"], vocab_size=m["vocab_size"],
+        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
+        act_impl=m["act_impl"], softmax_impl=m["softmax_impl"],
+        dtype=m["dtype"], qkv_bias=False, tie_embeddings=False,
+        kv_impl="paged", kv_block_len=engine["block_len"],
+        paged_attend_impl=engine["paged_attend_impl"])
 
 
 def dims(m: dict):
